@@ -46,6 +46,7 @@ from repro.core import (BayesSplitEdge, BatchedBayesSplitEdge, Scenario,
 from repro.core.acquisition import compile_counters
 from repro.core.batch_bo import (make_hetero_scenarios, make_mixed_scenarios,
                                  make_vgg19_scenarios, run_packed_shards)
+from repro.launch.compile_cache import place_compile_cache
 
 
 def _legacy_maximize(gp, problem, weights, t_norm, best_feasible, grid,
@@ -1109,6 +1110,8 @@ def run(n_scenarios: int = 16, budget: int = 20, repeats: int = 1,
 
     report = dict(
         backend=jax.default_backend(),
+        device_kind=jax.devices()[0].device_kind,
+        n_devices=n_devices,
         n_scenarios=n_scenarios,
         budget=budget,
         # 'before': seed implementation — fresh jit closures every BO
@@ -1137,7 +1140,6 @@ def run(n_scenarios: int = 16, budget: int = 20, repeats: int = 1,
             else round(lane_stats["occupancy_mean"], 3)),
         # scenario sharding (None on single-device hosts)
         sharded_s=None if sharded_s is None else round(sharded_s, 4),
-        n_devices=n_devices,
         # weak-scaling ceiling on forced-host-device runs is
         # cpu_count / n_devices (shards share the physical cores)
         cpu_count=os.cpu_count(),
@@ -1287,6 +1289,7 @@ def main():
                          "wholerun/streaming/packed shards + the shard-"
                          "packing padding win; --no-lm disables)")
     args = ap.parse_args()
+    place_compile_cache()
     r = run(args.scenarios, args.budget, args.repeats, args.legacy,
             mixed=args.mixed_arch, compaction=args.compaction,
             hetero=args.hetero, streaming=args.streaming,

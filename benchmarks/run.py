@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 from benchmarks import (fig6_convergence, fig7_space, fig8_regret,
                         fig9_ablation, fig10_seeds, profiling,
@@ -51,9 +52,12 @@ BENCHES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Run the selected benchmarks (all by default); the exit code is the
+    number of benchmarks that raised, so a failure never reads as 0."""
     names = set(sys.argv[1:])
     print("benchmark,seconds,derived")
+    failed = []
     for name, fn, derived in BENCHES:
         if names and name not in names:
             continue
@@ -62,10 +66,15 @@ def main() -> None:
         try:
             out = fn()
             d = derived(out)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest
+            traceback.print_exc()
             d = f"ERROR {type(e).__name__}: {e}"
+            failed.append(name)
         print(f"CSV,{name},{time.time() - t0:.1f},{d}", flush=True)
+    if failed:
+        print(f"failed: {', '.join(failed)}", file=sys.stderr)
+    return len(failed)
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -61,7 +61,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
-from repro.compat import shard_map
 from repro.core import gp as gpm
 from repro.core import jax_cost as jc
 from repro.core import surrogate as smod
@@ -896,9 +895,9 @@ def whole_run_sharded(stacked, grid, wvec, cfg: WholeRunConfig, mesh: Mesh):
     guaranteed equivalent to the unsharded program only within the
     studied trace tolerance (empirically bitwise on multi-lane shards).
     """
-    f = shard_map(lambda st, g, w: _whole_run(st, g, w, cfg)[0], mesh=mesh,
-                  in_specs=(PS("scen"), PS(), PS()), out_specs=PS("scen"),
-                  check_vma=False)
+    f = jax.shard_map(lambda st, g, w: _whole_run(st, g, w, cfg)[0],
+                      mesh=mesh, in_specs=(PS("scen"), PS(), PS()),
+                      out_specs=PS("scen"), check_vma=False)
     return f(stacked, grid, wvec)
 
 
@@ -1085,8 +1084,9 @@ class WholeRunBayesSplitEdge:
                           for tk in ("log_ls", "log_sv", "log_nv")}
         return final
 
-    def run(self) -> List[BOResult]:
-        cfg = WholeRunConfig(
+    def run_config(self) -> WholeRunConfig:
+        """The static program configuration ``run`` compiles against."""
+        return WholeRunConfig(
             n_init=self.n_init, n_max_repeat=self.n_max_repeat,
             # the ledger must hold the full init design even when a
             # scenario's budget is below n_init (the host engines still
@@ -1099,6 +1099,9 @@ class WholeRunBayesSplitEdge:
             use_schedules=self.use_schedules, warm_start=self.warm_start,
             gp=self.gp_cfg, surrogate=self.surrogate,
             use_prior=self.bank is not None)
+
+    def run(self) -> List[BOResult]:
+        cfg = self.run_config()
         wvec = acq_wvec(self.weights)
         stacked = self._stacked()
         grid = jnp.asarray(self.grid, jnp.float32)

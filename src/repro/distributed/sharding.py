@@ -15,8 +15,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
-from repro.compat import mesh_shape
-
 
 # Logical axes that appear in the model code.
 #   layers   - stacked scan dimension (never sharded)
@@ -42,7 +40,7 @@ class ShardCtx:
 
     @property
     def axis_sizes(self) -> Dict[str, int]:
-        return mesh_shape(self.mesh)
+        return dict(self.mesh.shape)
 
     def spec(self, axes: Tuple[Optional[str], ...]) -> PS:
         mapped = []
@@ -69,7 +67,7 @@ def build_rules(cfg, mesh: Mesh, *, fsdp: bool = False,
                 seq_parallel: bool = False,
                 dp_over_pod: bool = True) -> Dict[str, Optional[str]]:
     """Divisibility-aware logical->mesh mapping for one architecture."""
-    sizes = mesh_shape(mesh)
+    sizes = dict(mesh.shape)
     model = sizes.get("model", 1)
     data_axes: Tuple[str, ...] = ("data",) if "data" in sizes else ()
     if "pod" in sizes and dp_over_pod:

@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
-
 NEG_INF = -1e30
 
 
@@ -89,7 +85,7 @@ def decode_attention_kernel(q, k, v, kv_pos, q_pos, *, window: int = 0,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((hd,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_pos, q, k, v, kv_pos)

@@ -35,11 +35,15 @@ def matern_score(cand, x, alpha, mask, ls, sv, *, block_n: int = 128,
     pn = (-N) % bn
     pm = (-n) % 8
     f32 = jnp.float32
-    cand = jnp.pad(cand.astype(f32), ((0, 0), (0, pn), (0, 0)))
+    # candidates on the lane axis; per-scenario vectors as (S, 1, n)
+    # rows and scalars as (S, 1, 1) — see kernel.py for the tiling
+    cand_t = jnp.pad(cand.astype(f32), ((0, 0), (0, pn), (0, 0)))
+    cand_t = jnp.swapaxes(cand_t, 1, 2)
     x = jnp.pad(x.astype(f32), ((0, 0), (0, pm), (0, 0)))
-    alpha = jnp.pad(alpha.astype(f32), ((0, 0), (0, pm)))
-    mask = jnp.pad(mask.astype(f32), ((0, 0), (0, pm)))
-    out = matern_score_kernel(cand, x, alpha, mask,
-                              ls.astype(f32), sv.astype(f32),
+    alpha = jnp.pad(alpha.astype(f32), ((0, 0), (0, pm)))[:, None, :]
+    mask = jnp.pad(mask.astype(f32), ((0, 0), (0, pm)))[:, None, :]
+    out = matern_score_kernel(cand_t, x, alpha, mask,
+                              ls.astype(f32).reshape(S, 1, 1),
+                              sv.astype(f32).reshape(S, 1, 1),
                               block_n=bn, interpret=interpret)
-    return out[:, :N]
+    return out[:, 0, :N]

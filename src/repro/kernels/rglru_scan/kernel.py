@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
-
 
 def _kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref, h_scr, *,
             chunk: int, nc: int):
@@ -61,7 +57,7 @@ def rglru_scan_kernel(a, b, h0, *, chunk: int = 256, block_r: int = 512,
         out_shape=[jax.ShapeDtypeStruct((B, S, R), a.dtype),
                    jax.ShapeDtypeStruct((B, R), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((br,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

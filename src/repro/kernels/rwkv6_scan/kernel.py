@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
             s_scr, *, chunk: int, nc: int):
@@ -73,7 +69,7 @@ def rwkv6_scan_kernel(r, k, v, logw, u, s0, *, chunk: int = 128,
         out_shape=[jax.ShapeDtypeStruct((B, S, H, hd), r.dtype),
                    jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u, s0)

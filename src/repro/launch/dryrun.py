@@ -25,7 +25,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_dict as _cost_dict
 from repro.configs import SHAPES, get_config, list_configs, shape_applicable
 from repro.distributed.sharding import make_ctx, spec_tree, sharding_tree
 from repro.launch.mesh import make_production_mesh
@@ -186,7 +185,7 @@ def _rwkv_step_flops(cfg, batch_local: int, heads_local: int) -> float:
     args = (sh((B, H, hd, hd), jnp.float32),) + \
         tuple(sh((B, H, hd), jnp.float32) for _ in range(4)) + \
         (sh((H, hd), jnp.float32),)
-    c = _cost_dict(jax.jit(step).lower(*args).compile().cost_analysis())
+    c = jax.jit(step).lower(*args).compile().cost_analysis()
     return float(c.get("flops", 0.0))
 
 
@@ -216,7 +215,7 @@ def measure_analysis(cfg, shape, mesh, fsdp_mode: str = "always",
         ctx = _make_ctx_for(c2, mesh, shape, fsdp_mode, seq_parallel)
         lowered = _lower_cell(c2, shape, ctx, mesh)
         compiled = lowered.compile()
-        ca = _cost_dict(compiled.cost_analysis())
+        ca = compiled.cost_analysis()
         coll = _collective_bytes(compiled.as_text())
         return (float(ca.get("flops", 0.0)),
                 float(ca.get("bytes accessed", 0.0)), coll)
@@ -300,7 +299,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     # collectives only exist post-SPMD-partitioning -> compiled HLO.
     # NOTE: raw counts below see scan bodies once; the `analysis` block
     # holds the depth-extrapolated numbers §Roofline uses.

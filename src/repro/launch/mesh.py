@@ -21,12 +21,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     if override:
         dm = tuple(int(x) for x in override.split("x"))
         shape = ((2,) + dm) if multi_pod else dm
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / reduced dry-runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests / reduced dry-runs). Every axis is ``Auto``:
+    the model code places activations with ``with_sharding_constraint``,
+    which only accepts Auto axes."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants (per chip) — roofline denominators.

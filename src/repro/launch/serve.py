@@ -15,6 +15,7 @@ from repro.core.bo import BayesSplitEdge
 from repro.core.cost_model import Budgets, CostModel
 from repro.core.problem import SplitInferenceProblem
 from repro.core.profiles import lm_profile
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import transformer as tfm
 from repro.runtime.splitpoint import SplitRunner
 
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--e-max", type=float, default=0.0)
     ap.add_argument("--tau-max", type=float, default=0.0)
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     cfg = get_config(args.arch)
     exec_cfg = reduced(cfg) if args.reduced else cfg

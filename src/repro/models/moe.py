@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
-
 from repro.models.common import P
 from repro.models.mlp import mlp_template, mlp_apply
 
@@ -224,7 +222,7 @@ def moe_apply(p, x, cfg, ctx=None):
                 return (jax.lax.psum(out, axis),
                         jax.lax.pmean(aux, axis))
 
-            out, aux = shard_map(
+            out, aux = jax.shard_map(
                 f, mesh=mesh,
                 in_specs=(data_spec, PS(), PS(axis), ss, PS(axis), ss,
                           PS(axis), ss),
@@ -239,7 +237,7 @@ def moe_apply(p, x, cfg, ctx=None):
                     0, cfg.n_experts, 1)
                 return jax.lax.psum(out, axis), aux
 
-            out, aux = shard_map(
+            out, aux = jax.shard_map(
                 f, mesh=mesh,
                 in_specs=(data_spec, PS(), w_spec, ss, w_spec, ss,
                           wd_spec, ss),

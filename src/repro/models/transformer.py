@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
-
 from repro.models import attention as attn
 from repro.models import mlp as mlpm
 from repro.models import moe as moem
@@ -315,7 +313,7 @@ def embed_lookup(params, tokens, cfg, ctx):
         # ids must be replicated over `model` (the psum combines vocab
         # shards of the SAME positions); SP resharding happens after.
         ba = ctx.rules.get("batch")
-        return shard_map(
+        return jax.shard_map(
             f, mesh=mesh,
             in_specs=(PS(ctx.rules.get("vocab"), None), PS(ba, None)),
             out_specs=PS(ba, None, None),

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
 
 
 def _chunked_ce_dense(hidden, w, labels, n_chunks: int, vocab_valid: int):
@@ -116,7 +115,7 @@ def vocab_parallel_ce(hidden, unembed_w, labels, cfg, ctx,
     # pmax/psum combine is over vocab shards of the SAME tokens). Under
     # sequence parallelism jit inserts the trunk->loss all-gather here.
     ba = ctx.rules.get("batch")
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(PS(ba, None, None),
                   PS(None, ctx.rules.get("vocab")),

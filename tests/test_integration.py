@@ -71,11 +71,36 @@ def test_grad_compression_training_still_converges(tmp_path):
     assert losses[-1] < losses[0] - 0.05
 
 
-def test_serve_driver_places_split():
+def test_serve_driver_places_split(monkeypatch, tmp_path):
     from repro.launch import serve as serve_mod
+    # with the variable set, the driver leaves the process's compile-cache
+    # settings alone (the other tests of this worker keep theirs)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
     res = serve_mod.main(["--arch", "recurrentgemma-2b", "--reduced",
                           "--budget", "10"])
     assert res.n_evals <= 10
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_benchmark_runner_exits_nonzero_on_failure(monkeypatch, capsys):
+    """A benchmark that raises is reported and counted in the exit code;
+    the others still run."""
+    from benchmarks import run as bench_run
+
+    def boom():
+        raise RuntimeError("broken benchmark")
+
+    monkeypatch.setattr(bench_run, "BENCHES", [
+        ("ok", lambda: 3, lambda o: f"{o} rows"),
+        ("boom", boom, lambda o: ""),
+    ])
+    monkeypatch.setattr(sys, "argv", ["run"])
+    assert bench_run.main() == 1
+    out = capsys.readouterr()
+    assert "CSV,ok," in out.out and "3 rows" in out.out
+    assert "ERROR RuntimeError: broken benchmark" in out.out
+    assert "failed: boom" in out.err
 
 
 def test_dryrun_cell_on_ci_mesh():

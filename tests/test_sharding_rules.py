@@ -17,8 +17,7 @@ from repro.train.optimizer import adafactor, adamw, cosine_schedule
 
 def _fake_mesh(shape, axes):
     """AbstractMesh-backed spec checks (no devices needed)."""
-    from repro.compat import abstract_mesh
-    return abstract_mesh(shape, axes)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 MESHES = [((16, 16), ("data", "model")),

@@ -146,6 +146,9 @@ def main() -> int:
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.devices}").strip()
 
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
+
     from benchmarks.bench_engine import run
 
     # legacy baseline disabled: the gate compares against the current
