@@ -1,0 +1,8 @@
+"""95th percentile emit-minus-due time of every request due in the
+window (ms); a request that never emitted is infinitely late."""
+from bench.lib.latency import percentile
+
+
+def read(record):
+    lat = record["latencies_ms"]
+    return None if lat is None else percentile(lat, 95)
