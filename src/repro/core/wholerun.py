@@ -325,24 +325,30 @@ def _make_body(run_data, grid, wvec, cfg: WholeRunConfig, m: int):
             # mixing unseeded (just-admitted) and seeded lanes pays
             # both fits once and selects per lane — only admission
             # boundaries in the streaming engine hit that branch
-            if cfg.warm_start:
-                all_cold = ~jnp.any(st["active"] & st["seeded"])
+            with jax.named_scope("gp_fit"):
+                if cfg.warm_start:
+                    all_cold = ~jnp.any(st["active"] & st["seeded"])
 
-                def mixed_fit(data_, theta0_):
-                    gp_c, steps_c = cold_fit(data_, theta0_)
-                    gp_w, steps_w = warm_fit(data_, theta0_)
-                    gp = jax.tree.map(partial(_sel, st["seeded"]),
-                                      gp_w, gp_c)
-                    return gp, jnp.where(st["seeded"], steps_w, steps_c)
+                    def mixed_fit(data_, theta0_):
+                        gp_c, steps_c = cold_fit(data_, theta0_)
+                        gp_w, steps_w = warm_fit(data_, theta0_)
+                        gp = jax.tree.map(partial(_sel, st["seeded"]),
+                                          gp_w, gp_c)
+                        return gp, jnp.where(st["seeded"], steps_w,
+                                             steps_c)
 
-                gp_b, steps = jax.lax.cond(
-                    all_cold, cold_fit,
-                    lambda d, t0: jax.lax.cond(any_unseeded, mixed_fit,
-                                               warm_fit, d, t0),
-                    data, theta0)
-            else:
-                gp_b, steps = cold_fit(data, theta0)
+                    gp_b, steps = jax.lax.cond(
+                        all_cold, cold_fit,
+                        lambda d, t0: jax.lax.cond(any_unseeded, mixed_fit,
+                                                   warm_fit, d, t0),
+                        data, theta0)
+                else:
+                    gp_b, steps = cold_fit(data, theta0)
+            with jax.named_scope("acquisition"):
+                a_acq = acquire(gp_b)
+            return gp_b["theta"], steps, a_acq
 
+        def acquire(gp_b):
             cand_b = jax.vmap(
                 lambda p1, b1, a1, h1: assemble_candidates_dev(
                     p1, grid, b1, a1, h1, cfg.constraint_aware))(
@@ -375,9 +381,8 @@ def _make_body(run_data, grid, wvec, cfg: WholeRunConfig, m: int):
                     wvec["beta"], jnp.float32(REFINE_LR), REFINE_STEPS,
                     penalties=pen1, surrogate=cfg.surrogate)
                 return a
-            a_acq = jax.vmap(one_max)(gp_b, params, cand_b, bf,
-                                      lam_b, lam_g, pen_b)
-            return gp_b["theta"], steps, a_acq
+            return jax.vmap(one_max)(gp_b, params, cand_b, bf,
+                                     lam_b, lam_g, pen_b)
 
         def probe_only(theta0):
             return (theta0, jnp.zeros((s,), jnp.int32),
@@ -408,30 +413,31 @@ def _make_body(run_data, grid, wvec, cfg: WholeRunConfig, m: int):
         # every lane stepped this iteration is seeded from now on
         # (frozen lanes keep their flag via the freeze select below)
         st2["seeded"] = jnp.ones_like(st["seeded"])
-        st2 = jax.vmap(lambda s1, a, p1, b: _step(s1, a, p1, b, cfg))(
-            st2, a_next, params, run_data["budget"])
-        # divergence quarantine: a lane whose GP dataset went non-finite
-        # (a poisoned observation) must not fit on it — the lane's step
-        # is suppressed via the freeze select below, its `fault` flag
-        # raises and it deactivates: a retirement event the phase-loop
-        # exits surface to the host driver, which escalates (requeue /
-        # re-seed -> scrub -> degraded retirement). Healthy data is
-        # always finite, so `bad` is all False and the select keeps the
-        # historical bitwise behavior; the strict detector additionally
-        # flags diverged refit carries / chosen points (opt-in — organic
-        # warm-fit divergence was historically survivable).
-        bad = st["active"] & (
-            jnp.any(st["mask"] & ~jnp.isfinite(st["y"]), axis=1)
-            | jnp.any(st["mask"]
-                      & ~jnp.all(jnp.isfinite(st["x"]), axis=-1), axis=1))
-        if cfg.fault_on_divergence:
-            bad = bad | (st["active"] & (
-                (~gpm.theta_finite(theta) & upd)
-                | ~jnp.all(jnp.isfinite(a_next), axis=1)))
-        # freeze finished scenarios (early-stop masking) + faulted lanes
-        new = jax.tree.map(partial(_sel, st["active"] & ~bad), st2, st)
-        new["fault"] = st["fault"] | bad
-        new["active"] = new["active"] & ~bad
+        with jax.named_scope("oracle_step"):
+            st2 = jax.vmap(lambda s1, a, p1, b: _step(s1, a, p1, b, cfg))(
+                st2, a_next, params, run_data["budget"])
+            # divergence quarantine: a lane whose GP dataset went non-finite
+            # (a poisoned observation) must not fit on it — the lane's step
+            # is suppressed via the freeze select below, its `fault` flag
+            # raises and it deactivates: a retirement event the phase-loop
+            # exits surface to the host serve loop, which escalates (requeue /
+            # re-seed -> scrub -> degraded retirement). Healthy data is
+            # always finite, so `bad` is all False and the select keeps the
+            # historical bitwise behavior; the strict detector additionally
+            # flags diverged refit carries / chosen points (opt-in — organic
+            # warm-fit divergence was historically survivable).
+            bad = st["active"] & (
+                jnp.any(st["mask"] & ~jnp.isfinite(st["y"]), axis=1)
+                | jnp.any(st["mask"]
+                          & ~jnp.all(jnp.isfinite(st["x"]), axis=-1), axis=1))
+            if cfg.fault_on_divergence:
+                bad = bad | (st["active"] & (
+                    (~gpm.theta_finite(theta) & upd)
+                    | ~jnp.all(jnp.isfinite(a_next), axis=1)))
+            # freeze finished scenarios (early-stop masking) + faulted lanes
+            new = jax.tree.map(partial(_sel, st["active"] & ~bad), st2, st)
+            new["fault"] = st["fault"] | bad
+            new["active"] = new["active"] & ~bad
         return new, it + 1
 
     return body

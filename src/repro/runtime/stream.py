@@ -99,6 +99,7 @@ from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.distributed.sharding import (ADMISSION_POLICIES, admission_order,
                                         next_admission_shard,
                                         route_admission_shard)
+from repro.runtime import spans as spm
 
 # vocabulary of degraded-result reasons (checkpointed as codes — the
 # tuple is APPEND-ONLY: existing checkpoints store indices into it;
@@ -243,6 +244,14 @@ class _LanePool:
         mini-batch is always padded to the pool width so ``init_run``
         compiles exactly once per pool shape.
         """
+        eng = self.eng
+        with eng._spans.span("serve.admit", pool=self.pool_id, k=len(reqs),
+                             reqs=[idx for idx, _ in reqs],
+                             prestaged=sum(idx in eng._staged
+                                           for idx, _ in reqs)):
+            self._admit(reqs)
+
+    def _admit(self, reqs: Sequence) -> None:
         eng, k = self.eng, len(reqs)
         free = np.flatnonzero(self.order < 0)[:k]
         assert len(free) == k, "admission exceeds free lanes"
@@ -286,18 +295,21 @@ class _LanePool:
         compaction exit — run until live lanes halve — so the tail of
         the stream doesn't pay a host round-trip per retirement."""
         eng = self.eng
-        active = np.asarray(self.state["active"])
-        live = int(active.sum())
-        if live == 0:
-            return None
-        n_pts = np.asarray(self.state["n_pts"])
-        m = gpm.bucket_size(int(n_pts[active].max()),
-                            eng.cfg.gp.max_points)
-        last = m >= wr._final_bucket(eng.cfg)
-        live0 = (live // 2 + 1) if draining else live
-        self.state, self.it = wr.stream_phase(
-            self.run_data, self.state, self.it, jnp.int32(live0),
-            eng.grid, eng.wvec, eng.cfg, m, last)
+        with eng._spans.span("serve.dispatch", pool=self.pool_id):
+            active = np.asarray(self.state["active"])
+            live = int(active.sum())
+            eng._spans.note(live=live)
+            if live == 0:
+                return None
+            n_pts = np.asarray(self.state["n_pts"])
+            m = gpm.bucket_size(int(n_pts[active].max()),
+                                eng.cfg.gp.max_points)
+            eng._spans.note(bucket=m)
+            last = m >= wr._final_bucket(eng.cfg)
+            live0 = (live // 2 + 1) if draining else live
+            self.state, self.it = wr.stream_phase(
+                self.run_data, self.state, self.it, jnp.int32(live0),
+                eng.grid, eng.wvec, eng.cfg, m, last)
         return dict(pool=self.pool_id, lanes=self.width, live=live,
                     bucket=m)
 
@@ -307,9 +319,22 @@ class _LanePool:
         Returns ``(results, faulted lane rows, loop iterations since
         the last collect)``; faulted lanes (non-finite fit — frozen by
         the body with ``fault`` set) are NOT flushed: the engine runs
-        the quarantine ladder on them."""
+        the quarantine ladder on them.
+
+        The phase as the host sees it is the wait for ``active``
+        (``serve.wait``); the rest is the readback (``serve.readback``)."""
         if self.state is None:
             return [], [], 0
+        sp = self.eng._spans
+        with sp.span("serve.wait", pool=self.pool_id):
+            jax.block_until_ready(self.state["active"])
+        with sp.span("serve.readback", pool=self.pool_id):
+            out, faulted, iters = self._readback()
+            sp.note(rows=len(out), iters=iters,
+                    reqs=[r.index for r in out])
+        return out, faulted, iters
+
+    def _readback(self) -> Tuple[List[StreamResult], List[int], int]:
         active = np.asarray(self.state["active"])
         fault = np.asarray(self.state["fault"])
         rows = [r for r in range(self.width)
@@ -484,6 +509,14 @@ class StreamingBayesSplitEdge:
     * ``heartbeat_timeout_s`` — arm a ``HeartbeatMonitor`` over the
       pools; a pool silent for this long is declared dead and its
       in-flight requests re-enter the queue.
+
+    ``spans`` — a ``runtime.spans.Spans`` recorder: the serve loop then
+    records a span at each of its layer boundaries (``serve.round``,
+    ``serve.decode``, ``serve.admit``, ``serve.dispatch``,
+    ``serve.prestage``, ``serve.wait``, ``serve.readback``,
+    ``serve.idle``, ``serve.checkpoint``; ``docs/engine.md``). ``None``
+    records nothing. ``stream_stats()`` counts whether or not it is
+    given, and can be read while ``serve()`` runs.
     """
 
     name = "Streaming-Bayes-Split-Edge"
@@ -525,7 +558,7 @@ class StreamingBayesSplitEdge:
                  overload: str = "block",
                  routing: str = "score",
                  route_backoff_s: float = 0.05,
-                 route_max_retries: int = 3, **kw):
+                 route_max_retries: int = 3, spans=None, **kw):
         # BO-engine knobs (n_init, gp_cfg, warm_start, ...) arrive via
         # the shared EngineConfig; legacy keyword arguments fold over it
         # through the deprecation shim. l_pad is a *serving* static here
@@ -601,6 +634,7 @@ class StreamingBayesSplitEdge:
                          else [float(t) for t in arrivals])
         self.time_scale = float(time_scale)
         self.on_result = on_result
+        self._spans = spm.NULL if spans is None else spans
         self.config = config
         self.n_init = config.n_init
         self.weights = config.acq_weights()
@@ -633,7 +667,15 @@ class StreamingBayesSplitEdge:
         self._n_pulled = 0
         self._feed_done = False
         self._served = False
-        self._stats: dict = {}
+        # serving-loop accounting, live while serve() runs
+        # (stream_stats()); the per-dispatch traces are bounded so an
+        # unbounded feed doesn't grow host memory
+        self._t0: Optional[float] = None
+        self._t_end: Optional[float] = None
+        self._tally = dict(n_results=0, n_dispatches=0, lane_slots=0,
+                           n_flushed=0, qd_sum=0, qd_n=0, qd_max=0)
+        self._lane_log: deque = deque(maxlen=self.STATS_TRACE_CAP)
+        self._queue_depth: deque = deque(maxlen=self.STATS_TRACE_CAP)
         # fault tolerance ----------------------------------------------------
         self.admission_policy = admission_policy
         self.shed_hopeless = bool(shed_hopeless)
@@ -736,7 +778,8 @@ class StreamingBayesSplitEdge:
             if not self._arrived(self._n_pulled, now):
                 return
             try:
-                sc = next(self._feed)
+                with self._spans.span("serve.decode", req=self._n_pulled):
+                    sc = next(self._feed)
             except StopIteration:
                 self._feed_done = True
                 return
@@ -784,11 +827,15 @@ class StreamingBayesSplitEdge:
     def _prestage(self, pending: deque) -> None:
         """Stage every queued request now (called right after dispatch,
         so the host staging work overlaps the running device phase)."""
-        for idx, sc in pending:
-            if idx not in self._staged:
-                self._staged[idx] = wr.stage_scenario(
-                    sc, self.l_pad, self.n_init, self.constraint_aware,
-                    self.grid_np[:1], bank=self.bank)
+        with self._spans.span("serve.prestage"):
+            n = 0
+            for idx, sc in pending:
+                if idx not in self._staged:
+                    self._staged[idx] = wr.stage_scenario(
+                        sc, self.l_pad, self.n_init, self.constraint_aware,
+                        self.grid_np[:1], bank=self.bank)
+                    n += 1
+            self._spans.note(n=n)
 
     # -- fault handling ------------------------------------------------------
     def _handle_fault(self, pool: _LanePool, lane: int,
@@ -1110,7 +1157,8 @@ class StreamingBayesSplitEdge:
     def _maybe_checkpoint(self) -> None:
         if (self.ckpt_dir and self.ckpt_every
                 and self._round % self.ckpt_every == 0):
-            self.checkpoint_now()
+            with self._spans.span("serve.checkpoint"):
+                self.checkpoint_now()
 
     @classmethod
     def resume(cls, ckpt_dir: str, requests: Iterable[Scenario],
@@ -1247,18 +1295,16 @@ class StreamingBayesSplitEdge:
         pending = self._pending
         if self._restore is not None:
             self._replay_feed(pending)
-        # per-dispatch traces are bounded so an unbounded feed doesn't
-        # grow host memory; the aggregate stats accumulate separately
-        lane_log: deque = deque(maxlen=self.STATS_TRACE_CAP)
-        queue_depth: deque = deque(maxlen=self.STATS_TRACE_CAP)
-        n_results = n_dispatches = slots_total = n_flushed = 0
-        qd_sum = qd_n = qd_max = 0
-        t0 = time.monotonic()
+        sp = self._spans
+        t0_ns = time.monotonic_ns()
+        t0 = self._t0 = t0_ns * 1e-9
+        sp.begin(t0_ns)
         c = self._counters
+        tally = self._tally
 
-        def emit(res):
-            nonlocal n_results
-            n_results += 1
+        def give(res):
+            # no span stays open while the consumer holds the result
+            tally["n_results"] += 1
             self._n_evals_total += res.result.n_evals
             self._emitted.add(res.index)
             if res.degraded:
@@ -1270,9 +1316,10 @@ class StreamingBayesSplitEdge:
                     c["deadline_hits"] += 1
             if self.on_result is not None:
                 self.on_result(res)
+            with sp.gap():
+                yield res
 
         def flush(pool, entry=None):
-            nonlocal n_dispatches, slots_total, n_flushed
             flushed, faulted, iters = pool.collect()
             if entry is not None:
                 entry["iters"] = iters
@@ -1293,215 +1340,206 @@ class StreamingBayesSplitEdge:
                                   + 0.7 * pool.ewma_free)
                 if self.monitor is not None and not pool.muted:
                     self.monitor.report(pool.pool_id, wall)
-                lane_log.append(entry)
-                n_dispatches += 1
-                slots_total += entry["lanes"] * iters
+                self._lane_log.append(entry)
+                tally["n_dispatches"] += 1
+                tally["lane_slots"] += entry["lanes"] * iters
             for lane in faulted:
                 self._handle_fault(pool, lane, pending)
             now_trace = self._now_trace(time.monotonic() - t0)
             for res in flushed:
                 res.emit_s = now_trace
-                n_flushed += 1
-                emit(res)
-                yield res
+                tally["n_flushed"] += 1
+                yield from give(res)
 
-        while True:
-            self._round += 1
-            now = time.monotonic() - t0
-            # snapshot FIRST: a crash anywhere in the round (chaos's
-            # kill model) resumes from a commit no older than one round
-            self._maybe_checkpoint()
-            if self.monitor is not None:
-                for p in self._pools:
-                    if not p.dead and not p.muted:
-                        # liveness-only ping: real step times reach the
-                        # monitor from the dispatch flush, so the
-                        # straggler statistics stay meaningful
-                        self.monitor.heartbeat(p.pool_id)
-                for h in self.monitor.dead():
-                    self._drop_pool(h, reason="heartbeat-timeout")
-                if self.routing == "score":
-                    # failover ladder: backoff -> rebalance -> drop,
-                    # all BEFORE the hard heartbeat timeout would fire
-                    self._failover_step(now)
-            else:
-                # a muted pool can only ever be detected by the
-                # monitor; without one, drop it immediately
-                for p in self._pools:
-                    if p.muted and not p.dead:
-                        self._drop_pool(p.pool_id, reason="muted")
-            self._pull(pending, now)
-            while self._overflow:
-                # host-side degraded answers minted by the pull
-                # (oversized/overload rejections, overflow sheds)
-                res = self._overflow.popleft()
-                emit(res)
-                yield res
-            if self.shed_hopeless and pending:
-                # triage BEFORE admission: a request that cannot make
-                # its deadline must not take a lane from one that can
-                now_trace = self._now_trace(time.monotonic() - t0)
-                keep = deque()
-                for idx, sc in pending:
-                    if self._hopeless(sc, now_trace):
-                        c["n_shed"] += 1
-                        res = self._host_result(idx, sc, now_trace,
-                                                "shed")
-                        emit(res)
-                        yield res
-                    else:
-                        keep.append((idx, sc))
-                pending = self._pending = keep
-            if self.elastic:
-                # resize BEFORE admission so this round's fills see the
-                # new width (grow under pressure, shrink when idle)
-                self._elastic_step(len(pending))
-            # policy-ordered admission into the best shard — requests
-            # bind to exactly one pool, so the multi-pool path stays
-            # collective-free. "score" places by free capacity
-            # discounted by health (EWMA dispatch wall, heartbeat
-            # staleness, backoff); on a healthy fleet it reduces
-            # exactly to the historical most-free/round-robin ("rr").
-            fills: dict = {i: [] for i in range(self.n_shards)}
-            if pending:
-                queue = list(pending)
-                sel = admission_order(queue, self._now_trace(now),
-                                      self.admission_policy)
-                feats = (self._route_features(now)
-                         if self.routing == "score" else None)
-                wall_ref = None
-                if feats is not None:
-                    walls = [p.ewma_wall for p in self._pools
-                             if not p.dead and p.ewma_wall is not None]
-                    wall_ref = (float(np.median(walls))
-                                if walls else None)
-                taken = set()
-                for j in sel:
-                    if feats is not None:
+        try:
+            while True:
+                self._round += 1
+                with sp.span("serve.round", annotate=False,
+                             round=self._round):
+                    now = time.monotonic() - t0
+                    # snapshot FIRST: a crash anywhere in the round
+                    # (chaos's kill model) resumes from a commit no
+                    # older than one round
+                    self._maybe_checkpoint()
+                    if self.monitor is not None:
                         for p in self._pools:
-                            if not (p.dead or p.muted):
-                                feats[p.pool_id]["free"] = (
-                                    p.free_count()
-                                    - len(fills[p.pool_id]))
-                        shard = route_admission_shard(
-                            feats, self._rr, wall_ref=wall_ref)
+                            if not p.dead and not p.muted:
+                                # liveness-only ping: real step times
+                                # reach the monitor from the dispatch
+                                # flush, so the straggler statistics
+                                # stay meaningful
+                                self.monitor.heartbeat(p.pool_id)
+                        for h in self.monitor.dead():
+                            self._drop_pool(h, reason="heartbeat-timeout")
+                        if self.routing == "score":
+                            # failover ladder: backoff -> rebalance ->
+                            # drop, all BEFORE the hard heartbeat
+                            # timeout would fire
+                            self._failover_step(now)
                     else:
-                        free = [p.free_count() - len(fills[p.pool_id])
-                                for p in self._pools]
-                        shard = next_admission_shard(free, self._rr)
-                    if shard is None:
-                        break
-                    self._rr = (shard + 1) % self.n_shards
-                    fills[shard].append(queue[j])
-                    taken.add(j)
-                if taken:
-                    pending = self._pending = deque(
-                        q for k, q in enumerate(queue) if k not in taken)
-            for i, reqs in fills.items():
-                if reqs:
-                    self._pools[i].admit(reqs)
-            if pending and all(p.dead for p in self._pools):
-                raise RuntimeError(
-                    "all lane pools lost — cannot serve the queue")
-            # inject AFTER admission so poison/drop faults see the
-            # round's in-flight lanes; the kill model still crashes
-            # between the round's checkpoint and its dispatches (the
-            # admissions above are device-state only — the snapshot
-            # keeps those requests pending, so resume re-admits them)
-            if self.chaos is not None:
-                self.chaos.inject(self)     # may raise SimulatedCrash
-            if self.shed_hopeless:
-                self._preempt(self._now_trace(time.monotonic() - t0))
-            queue_depth.append(len(pending))
-            qd_sum += len(pending)
-            qd_n += 1
-            qd_max = max(qd_max, len(pending))
-            # lanes whose budget <= n_init retire at the init design —
-            # flush them (plus preempted/quarantine-retired lanes)
-            # before (possibly instead of) any dispatch
-            for p in self._pools:
-                yield from flush(p)
-            draining = self._feed_done and not pending
-            dispatched = []
-            for p in self._pools:
-                if p.dead or p.muted:
-                    continue
-                if p.live_count() > 0:
-                    # timing starts BEFORE the chaos hook: an injected
-                    # straggler delay is exactly the slow-host cost the
-                    # per-pool EWMA wall is supposed to see
-                    t_d = time.monotonic()
+                        # a muted pool can only ever be detected by the
+                        # monitor; without one, drop it immediately
+                        for p in self._pools:
+                            if p.muted and not p.dead:
+                                self._drop_pool(p.pool_id, reason="muted")
+                    self._pull(pending, now)
+                    while self._overflow:
+                        # host-side degraded answers minted by the pull
+                        # (oversized/overload rejections, overflow sheds)
+                        yield from give(self._overflow.popleft())
+                    if self.shed_hopeless and pending:
+                        # triage BEFORE admission: a request that cannot
+                        # make its deadline must not take a lane from
+                        # one that can
+                        now_trace = self._now_trace(time.monotonic() - t0)
+                        keep = deque()
+                        for idx, sc in pending:
+                            if self._hopeless(sc, now_trace):
+                                c["n_shed"] += 1
+                                yield from give(self._host_result(
+                                    idx, sc, now_trace, "shed"))
+                            else:
+                                keep.append((idx, sc))
+                        pending = self._pending = keep
+                    if self.elastic:
+                        # resize BEFORE admission so this round's fills
+                        # see the new width (grow under pressure, shrink
+                        # when idle)
+                        self._elastic_step(len(pending))
+                    # policy-ordered admission into the best shard —
+                    # requests bind to exactly one pool, so the
+                    # multi-pool path stays collective-free. "score"
+                    # places by free capacity discounted by health (EWMA
+                    # dispatch wall, heartbeat staleness, backoff); on a
+                    # healthy fleet it reduces exactly to the historical
+                    # most-free/round-robin ("rr").
+                    fills: dict = {i: [] for i in range(self.n_shards)}
+                    if pending:
+                        queue = list(pending)
+                        sel = admission_order(queue, self._now_trace(now),
+                                              self.admission_policy)
+                        feats = (self._route_features(now)
+                                 if self.routing == "score" else None)
+                        wall_ref = None
+                        if feats is not None:
+                            walls = [p.ewma_wall for p in self._pools
+                                     if not p.dead
+                                     and p.ewma_wall is not None]
+                            wall_ref = (float(np.median(walls))
+                                        if walls else None)
+                        taken = set()
+                        for j in sel:
+                            if feats is not None:
+                                for p in self._pools:
+                                    if not (p.dead or p.muted):
+                                        feats[p.pool_id]["free"] = (
+                                            p.free_count()
+                                            - len(fills[p.pool_id]))
+                                shard = route_admission_shard(
+                                    feats, self._rr, wall_ref=wall_ref)
+                            else:
+                                free = [p.free_count()
+                                        - len(fills[p.pool_id])
+                                        for p in self._pools]
+                                shard = next_admission_shard(free, self._rr)
+                            if shard is None:
+                                break
+                            self._rr = (shard + 1) % self.n_shards
+                            fills[shard].append(queue[j])
+                            taken.add(j)
+                        if taken:
+                            pending = self._pending = deque(
+                                q for k, q in enumerate(queue)
+                                if k not in taken)
+                    for i, reqs in fills.items():
+                        if reqs:
+                            self._pools[i].admit(reqs)
+                    if pending and all(p.dead for p in self._pools):
+                        raise RuntimeError(
+                            "all lane pools lost — cannot serve the queue")
+                    # inject AFTER admission so poison/drop faults see
+                    # the round's in-flight lanes; the kill model still
+                    # crashes between the round's checkpoint and its
+                    # dispatches (the admissions above are device-state
+                    # only — the snapshot keeps those requests pending,
+                    # so resume re-admits them)
                     if self.chaos is not None:
-                        self.chaos.on_dispatch(self, p)
-                    entry = p.dispatch(draining=draining)
-                    if entry is not None:
-                        entry["queue_depth"] = len(pending)
-                        entry["t0"] = t_d
-                        dispatched.append((p, entry))
-            # the device phases are in flight: overlap the host-side
-            # pull + staging of the queue with them
-            self._pull(pending, time.monotonic() - t0)
-            self._prestage(pending)
-            for p, entry in dispatched:
-                yield from flush(p, entry)
-            while self._overflow:
-                res = self._overflow.popleft()
-                emit(res)
-                yield res
-            if not dispatched:
-                inflight = any(
-                    bool(np.any(p.order >= 0)) for p in self._pools
-                    if not p.dead)
-                if self._feed_done and not pending and not inflight:
-                    break
-                if inflight:
-                    # only unreachable (muted) pools hold work — wait
-                    # for the heartbeat verdict instead of busy-spinning
-                    time.sleep(0.005)
-                elif pending:
-                    # every pool is in its failover backoff window —
-                    # wait it out instead of busy-spinning
-                    time.sleep(0.002)
-                elif not pending and self.arrivals is not None:
-                    # idle server: sleep until the next arrival
-                    t_next = (self.arrivals[self._n_pulled]
-                              * self.time_scale
-                              if self._n_pulled < len(self.arrivals)
-                              else 0.0)
-                    dt = t_next - (time.monotonic() - t0)
-                    if dt > 0:
-                        time.sleep(dt)
-            elif self._feed_done and not pending:
-                # drain mode: no admissions left — shrink pools so the
-                # tail doesn't pay for freed lanes
-                for p in self._pools:
-                    if not p.dead:
-                        p.shrink()
-
-        wall = time.monotonic() - t0
-        # loop evals from the flushed results themselves (every retired
-        # request's post-init evaluations): lane_log's per-dispatch
-        # `live` is the ENTRY count, which overcounts draining
-        # dispatches where lanes retire mid-phase
-        evals = self._n_evals_total - self.n_init * n_flushed
-        self._stats = dict(
-            n_results=n_results, n_dispatches=n_dispatches,
-            lane_slots=slots_total, loop_evals=evals,
-            occupancy_mean=(evals / slots_total if slots_total else 1.0),
-            queue_depth_mean=(qd_sum / qd_n if qd_n else 0.0),
-            queue_depth_max=qd_max,
-            wall_s=wall,
-            arrivals_per_s=(n_results / wall if wall > 0 else 0.0),
-            rounds=self._round,
-            deadline_hit_rate=(
-                c["deadline_hits"] / c["deadline_total"]
-                if c["deadline_total"] else 1.0),
-            max_pending=self.max_pending,
-            pool_widths=[p.width for p in self._pools],
-            **dict(c),
-            # bounded traces (the STATS_TRACE_CAP most recent entries)
-            lane_log=list(lane_log), queue_depth=list(queue_depth),
-            resize_log=list(self._resize_log))
+                        self.chaos.inject(self)   # may raise SimulatedCrash
+                    if self.shed_hopeless:
+                        self._preempt(self._now_trace(
+                            time.monotonic() - t0))
+                    self._queue_depth.append(len(pending))
+                    tally["qd_sum"] += len(pending)
+                    tally["qd_n"] += 1
+                    tally["qd_max"] = max(tally["qd_max"], len(pending))
+                    # lanes whose budget <= n_init retire at the init
+                    # design — flush them (plus preempted/quarantine-
+                    # retired lanes) before (possibly instead of) any
+                    # dispatch
+                    for p in self._pools:
+                        yield from flush(p)
+                    draining = self._feed_done and not pending
+                    dispatched = []
+                    for p in self._pools:
+                        if p.dead or p.muted:
+                            continue
+                        if p.live_count() > 0:
+                            # timing starts BEFORE the chaos hook: an
+                            # injected straggler delay is exactly the
+                            # slow-host cost the per-pool EWMA wall is
+                            # supposed to see
+                            t_d = time.monotonic()
+                            if self.chaos is not None:
+                                self.chaos.on_dispatch(self, p)
+                            entry = p.dispatch(draining=draining)
+                            if entry is not None:
+                                entry["queue_depth"] = len(pending)
+                                entry["t0"] = t_d
+                                dispatched.append((p, entry))
+                    # the device phases are in flight: overlap the
+                    # host-side pull + staging of the queue with them
+                    self._pull(pending, time.monotonic() - t0)
+                    self._prestage(pending)
+                    for p, entry in dispatched:
+                        yield from flush(p, entry)
+                    while self._overflow:
+                        yield from give(self._overflow.popleft())
+                    if not dispatched:
+                        inflight = any(
+                            bool(np.any(p.order >= 0)) for p in self._pools
+                            if not p.dead)
+                        if self._feed_done and not pending and not inflight:
+                            break
+                        if inflight:
+                            # only unreachable (muted) pools hold work —
+                            # wait for the heartbeat verdict instead of
+                            # busy-spinning
+                            with sp.span("serve.idle", why="muted"):
+                                time.sleep(0.005)
+                        elif pending:
+                            # every pool is in its failover backoff
+                            # window — wait it out instead of
+                            # busy-spinning
+                            with sp.span("serve.idle", why="backoff"):
+                                time.sleep(0.002)
+                        elif not pending and self.arrivals is not None:
+                            # idle server: sleep until the next arrival
+                            t_next = (self.arrivals[self._n_pulled]
+                                      * self.time_scale
+                                      if self._n_pulled < len(self.arrivals)
+                                      else 0.0)
+                            dt = t_next - (time.monotonic() - t0)
+                            if dt > 0:
+                                with sp.span("serve.idle", why="arrival"):
+                                    time.sleep(dt)
+                    elif self._feed_done and not pending:
+                        # drain mode: no admissions left — shrink pools
+                        # so the tail doesn't pay for freed lanes
+                        for p in self._pools:
+                            if not p.dead:
+                                p.shrink()
+        finally:
+            self._t_end = time.monotonic()
 
     def run(self) -> List[BOResult]:
         """Drain the whole feed; results in arrival order (the newly
@@ -1513,10 +1551,42 @@ class StreamingBayesSplitEdge:
         return [out[i] for i in sorted(out)]
 
     def stream_stats(self) -> dict:
-        """Serving-loop accounting of the last ``serve``/``run``:
-        dispatch count, lane-slot occupancy (live-lane evals over
-        computed lane slots), queue-depth trajectory and arrival
-        throughput, the per-dispatch lane log, plus the fault-tolerance
-        counters (faults, requeues, preemptions, sheds, pool drops,
-        checkpoints, deadline hit rate)."""
-        return dict(self._stats)
+        """Serving-loop accounting of ``serve``/``run``: dispatch count,
+        lane-slot occupancy (live-lane evals over computed lane slots),
+        queue-depth trajectory and arrival throughput, the per-dispatch
+        lane log, plus the fault-tolerance counters (faults, requeues,
+        preemptions, sheds, pool drops, checkpoints, deadline hit rate).
+        The running totals while ``serve()`` runs; its final totals once
+        it has ended; empty before it starts."""
+        if self._t0 is None:
+            return {}
+        t = self._tally
+        c = self._counters
+        wall = (time.monotonic() if self._t_end is None
+                else self._t_end) - self._t0
+        # loop evals from the flushed results themselves (every retired
+        # request's post-init evaluations): lane_log's per-dispatch
+        # `live` is the ENTRY count, which overcounts draining
+        # dispatches where lanes retire mid-phase
+        evals = self._n_evals_total - self.n_init * t["n_flushed"]
+        slots = t["lane_slots"]
+        return dict(
+            n_results=t["n_results"], n_dispatches=t["n_dispatches"],
+            lane_slots=slots, loop_evals=evals,
+            occupancy_mean=(evals / slots if slots else 1.0),
+            queue_depth_mean=(t["qd_sum"] / t["qd_n"] if t["qd_n"]
+                              else 0.0),
+            queue_depth_max=t["qd_max"],
+            wall_s=wall,
+            arrivals_per_s=(t["n_results"] / wall if wall > 0 else 0.0),
+            rounds=self._round,
+            deadline_hit_rate=(
+                c["deadline_hits"] / c["deadline_total"]
+                if c["deadline_total"] else 1.0),
+            max_pending=self.max_pending,
+            pool_widths=[p.width for p in self._pools],
+            **dict(c),
+            # bounded traces (the STATS_TRACE_CAP most recent entries)
+            lane_log=list(self._lane_log),
+            queue_depth=list(self._queue_depth),
+            resize_log=list(self._resize_log))
