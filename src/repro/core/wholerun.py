@@ -70,7 +70,7 @@ from repro.core.acquisition import (REFINE_LR, REFINE_STEPS, AcqWeights,
 from repro.core.batch_bo import Scenario
 from repro.core.bo import BOResult, _init_grid
 from repro.core.engine_config import EngineConfig, resolve_config
-from repro.core.priorbank import PriorBank, stage_prior
+from repro.core.priorbank import _THETA_KEYS, PriorBank, stage_prior
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,6 +273,83 @@ def _pen_static(params, grid, boundary):
 _OUT_KEYS = ("ev_u", "ev_acc", "ev_feas", "ev_trace", "ev_l", "n",
              "best_a", "best_u", "has_best", "fit_steps", "fit_calls",
              "gen", "fault")
+
+
+@jax.jit
+def _pack_words(tree) -> jax.Array:
+    """Every leaf of ``tree`` (32-bit or bool) as int32 words, bit for
+    bit, in one buffer: one transfer to the host in place of one per
+    leaf."""
+    words = []
+    for v in jax.tree.leaves(tree):
+        if v.dtype == jnp.bool_:
+            v = v.astype(jnp.int32)
+        elif v.dtype != jnp.int32:
+            v = jax.lax.bitcast_convert_type(v, jnp.int32)
+        words.append(v.reshape(-1))
+    return jnp.concatenate(words)
+
+
+def fetch_out(state: dict, theta: bool = False, it=None) -> dict:
+    """Everything a retirement flush reads, in one host fetch: the
+    whole-width ``_OUT_KEYS`` arrays and ``active``, the warm-start
+    ``theta`` leaves the prior bank records (``theta=True``) and the loop
+    counter ``it`` (when given), packed on the device into one buffer
+    (:func:`_pack_words`) and unpacked on the host. The pack is one
+    program per pool width, whatever the number of retiring lanes."""
+    tree = {k: state[k] for k in _OUT_KEYS + ("active",)}
+    if theta:
+        tree["theta"] = {k: state["theta"][k] for k in _THETA_KEYS}
+    if it is not None:
+        tree["it"] = it
+    leaves, treedef = jax.tree.flatten(tree)
+    words = np.asarray(_pack_words(tree))
+    out, at = [], 0
+    for v in leaves:
+        w = words[at:at + v.size]
+        at += v.size
+        if v.dtype == jnp.bool_:
+            w = w != 0
+        elif v.dtype != jnp.int32:
+            w = w.view(v.dtype)
+        out.append(w.reshape(v.shape))
+    return jax.tree.unflatten(treedef, out)
+
+
+def take_rows(snap: dict, rows: Sequence[int]) -> dict:
+    """Rows ``rows`` of a :func:`fetch_out` snapshot: the ``_OUT_KEYS``
+    (and ``theta``, if fetched), bitwise the device gather
+    ``state[k][rows]``. Fancy indexing copies, so a result that keeps a
+    row pins its own rows and not the snapshot."""
+    idx = np.asarray(rows, np.int64)
+    sub = {k: snap[k][idx] for k in _OUT_KEYS}
+    if "theta" in snap:
+        sub["theta"] = {k: v[idx] for k, v in snap["theta"].items()}
+    return sub
+
+
+def scatter_rows(final: dict, state: dict, rows: Sequence[int],
+                 order: np.ndarray, n: int) -> None:
+    """The offline compacted run's inverse scatter: write lane rows
+    ``rows`` of ``state`` (``_OUT_KEYS`` and the final warm-start
+    ``theta``, which the prior bank records) into scenario slots
+    ``order[r]`` of ``final``, host arrays of ``n`` rows made at first
+    use. Rows whose ``order`` is -1, padding lanes, are left out."""
+    rows = np.asarray([r for r in rows if order[r] >= 0], np.int64)
+    if not rows.size:
+        return
+    slots = order[rows]
+
+    def put(dst, src):
+        for k, v in src.items():
+            if isinstance(v, dict):
+                put(dst.setdefault(k, {}), v)
+                continue
+            if k not in dst:
+                dst[k] = np.zeros((n,) + v.shape[1:], v.dtype)
+            dst[k][slots] = v
+
+    put(final, take_rows(fetch_out(state, theta=True), rows))
 
 
 def _make_body(run_data, grid, wvec, cfg: WholeRunConfig, m: int):
@@ -852,7 +929,7 @@ def stack_staged(staged: Sequence[dict], l_pad: int, pad_to: int) -> dict:
             [st.get("bank_hit", False) for st in staged]), bool),
         theta0={k: jnp.asarray(np.asarray(
             [st.get("theta0", {}).get(k, 0.0) for st in staged]),
-            jnp.float32) for k in ("log_ls", "log_sv", "log_nv")},
+            jnp.float32) for k in _THETA_KEYS},
     )
 
 
@@ -1035,26 +1112,6 @@ class WholeRunBayesSplitEdge:
         order[n_real:] = -1
         final: dict = {}
 
-        def flush(st, rows):
-            """Inverse scatter for retiring lanes: device-gather just the
-            given rows and write them into their original scenario slots
-            (lanes still running are flushed once, at exit). The final
-            warm-start carry rides along for the prior bank's
-            retirement recording."""
-            rows = [r for r in rows if order[r] >= 0]
-            if not rows:
-                return
-            idx = jnp.asarray(np.asarray(rows))
-            sub = {k: np.asarray(st[k][idx]) for k in _OUT_KEYS}
-            for tk in ("log_ls", "log_sv", "log_nv"):
-                sub["theta/" + tk] = np.asarray(st["theta"][tk][idx])
-            for k, v in sub.items():
-                if k not in final:
-                    final[k] = np.zeros((n_real,) + v.shape[1:], v.dtype)
-            for j, r in enumerate(rows):
-                for k in final:
-                    final[k][order[r]] = sub[k][j]
-
         m_final = _final_bucket(cfg)
         it = jnp.int32(0)
         it_host = 0
@@ -1069,7 +1126,9 @@ class WholeRunBayesSplitEdge:
             s_next = _next_pow2(live.size)
             if s_next < active.shape[0]:
                 # retire exactly the lanes about to drop
-                flush(state, np.setdiff1d(np.arange(active.shape[0]), live))
+                scatter_rows(final, state,
+                             np.setdiff1d(np.arange(active.shape[0]), live),
+                             order, n_real)
                 state, run_data, keep = gather_live_lanes(
                     state, run_data, live, s_next)
                 order = np.where(np.arange(s_next) < live.size,
@@ -1081,13 +1140,12 @@ class WholeRunBayesSplitEdge:
                                  live=int(live.size), bucket=m,
                                  iters=it_new - it_host))
             it_host = it_new
-        flush(state, np.arange(state["n"].shape[0]))
+        scatter_rows(final, state, np.arange(state["n"].shape[0]), order,
+                     n_real)
         slots = sum(log["lanes"] * log["iters"] for log in lane_log)
         self._lane_stats = dict(
             n_dispatches=len(lane_log), lane_slots=slots,
             lane_log=lane_log)
-        final["theta"] = {tk: final.pop("theta/" + tk)
-                          for tk in ("log_ls", "log_sv", "log_nv")}
         return final
 
     def run_config(self) -> WholeRunConfig:

@@ -335,21 +335,17 @@ class _LanePool:
         return out, faulted, iters
 
     def _readback(self) -> Tuple[List[StreamResult], List[int], int]:
-        active = np.asarray(self.state["active"])
-        fault = np.asarray(self.state["fault"])
+        bank = self.eng.bank
+        snap = wr.fetch_out(self.state, theta=bank is not None, it=self.it)
+        active, fault = snap["active"], snap["fault"]
         rows = [r for r in range(self.width)
                 if self.order[r] >= 0 and not active[r] and not fault[r]]
         faulted = [r for r in range(self.width)
                    if self.order[r] >= 0 and fault[r]]
         out = []
         if rows:
-            idx = jnp.asarray(np.asarray(rows))
-            sub = {k: np.asarray(self.state[k][idx])
-                   for k in wr._OUT_KEYS}
-            bank = self.eng.bank
-            th = (None if bank is None else
-                  {k: np.asarray(self.state["theta"][k][idx])
-                   for k in ("log_ls", "log_sv", "log_nv")})
+            sub = wr.take_rows(snap, rows)
+            th = sub.get("theta")
             for j, r in enumerate(rows):
                 req_idx = int(self.order[r])
                 # evict: a long-lived server must not accumulate every
@@ -375,7 +371,7 @@ class _LanePool:
                     gen=int(self.gen[r]), raw=raw,
                     degraded=bool(reason), reason=reason))
                 self.order[r] = -1
-        it_new = int(self.it)
+        it_new = int(snap["it"])
         iters, self.it_host = it_new - self.it_host, it_new
         return out, faulted, iters
 

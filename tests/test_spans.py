@@ -5,6 +5,7 @@ One short served feed (two lanes, five requests on an arrival schedule,
 under a CPU profile) backs the tests that need a server."""
 import glob
 import os
+import resource
 import time
 
 import jax
@@ -33,6 +34,54 @@ STATS_KEYS = {
     "resize_log"}
 
 
+def _clocks():
+    """This thread's wall clock, CPU time and count of voluntary context
+    switches (blocking waits), for the off-CPU time between two reads."""
+    return (time.monotonic_ns(), time.thread_time_ns(),
+            resource.getrusage(resource.RUSAGE_THREAD).ru_nvcsw)
+
+
+def _off_cpu_ns(a, b) -> int:
+    """Wall time between clock reads ``a`` and ``b`` in which the thread
+    ran no code of its own, not having asked to wait: preempted by other
+    processes, or its virtual CPU taken by the host. A blocking wait in
+    between counts as the thread's own time (0 is returned)."""
+    if b[2] != a[2]:
+        return 0
+    return max(0, (b[0] - a[0]) - (b[1] - a[1]))
+
+
+class _ClockedSpan(spm._Span):
+    """A span that reads the thread's clocks on either side of entering
+    and of leaving (its profiler annotation included)."""
+
+    __slots__ = ("entered",)
+
+    def __enter__(self):
+        before = _clocks()
+        super().__enter__()
+        self.entered = (before, _clocks())
+        return self
+
+    def __exit__(self, *exc):
+        before = _clocks()
+        super().__exit__(*exc)
+        self.rec.clocks.append((self.entered, (before, _clocks())))
+        return False
+
+
+class _Spans(spm.Spans):
+    """The recorder, with ``clocks[j]``, the clock reads around entering
+    and leaving the span of ``rows[j]``."""
+
+    def __init__(self):
+        super().__init__()
+        self.clocks = []
+
+    def span(self, name, annotate=True, **attrs):
+        return _ClockedSpan(self, name, attrs, annotate)
+
+
 def _reqs():
     return [Scenario(default_vgg19_problem(), seed=s, budget=b)
             for s, b in ((0, 10), (1, 12), (2, 10), (3, 12), (4, 10))]
@@ -49,7 +98,7 @@ def served(tmp_path_factory):
             budget_max=12, **kw)
 
     engine().run()
-    rec = spm.Spans()
+    rec = _Spans()
     eng = engine(arrivals=ARRIVALS, spans=rec)
     tdir = str(tmp_path_factory.mktemp("trace"))
     live = []
@@ -57,10 +106,13 @@ def served(tmp_path_factory):
     opts.python_tracer_level = 0
     jax.profiler.start_trace(tdir, profiler_options=opts)
     try:
+        before = _clocks()
         with jax.profiler.TraceAnnotation(OUTER):
             outer_ns = time.monotonic_ns()
-            results = []
+            outer_clocks = (before, _clocks())
+            results, received = [], {}
             for res in eng.serve():
+                received[res.index] = _clocks()
                 results.append(res)
                 live.append(eng.stream_stats())
     finally:
@@ -71,7 +123,8 @@ def served(tmp_path_factory):
             for plane in pd.planes if plane.name == "/host:CPU"
             for line in plane.lines for ev in line.events]
     return dict(eng=eng, rec=rec, results=results, live=live, host=host,
-                outer_ns=outer_ns)
+                outer_ns=outer_ns, outer_clocks=outer_clocks,
+                received=received)
 
 
 def _rows(rec, name):
@@ -145,7 +198,12 @@ def test_every_request_is_decoded_and_admitted_once(served):
 def test_the_three_pieces_add_up_to_each_latency(served):
     """Due to admission, admission to the end of the wait before the
     readback that flushed it, and that readback: their sum is the
-    request's emit time minus its due time, within 1 ms."""
+    request's emit time minus its due time, within 1 ms of the program's
+    own time. Between the readback's end and the emit stamp a loaded
+    machine (other test workers, other guests of the host) can keep the
+    serving thread off its CPU for milliseconds; that time is read from
+    the thread's clocks, up to the first result of the flush, and is not
+    the program's."""
     rec = served["rec"]
     admit = {}
     for r in sorted(_rows(rec, "serve.admit"), key=lambda r: r[1]):
@@ -153,14 +211,20 @@ def test_the_three_pieces_add_up_to_each_latency(served):
             admit.setdefault(i, r[1])
     waits = sorted(_rows(rec, "serve.wait"), key=lambda r: r[2])
     emit = {res.index: res.emit_s for res in served["results"]}
+    assert len(rec.clocks) == len(rec.rows)
     checked = 0
-    for rb in _rows(rec, "serve.readback"):
+    for rb, (_, (leaving, _)) in zip(rec.rows, rec.clocks):
+        reqs = rb[4].get("reqs") if rb[0] == "serve.readback" else None
+        if not reqs:
+            continue
         w_end = [w[2] for w in waits if w[2] <= rb[1]][-1]
-        for i in rb[4]["reqs"]:
+        off_cpu = _off_cpu_ns(leaving, served["received"][reqs[0]])
+        for i in reqs:
             due = rec.t0_ns + ARRIVALS[i] * 1e9
             pieces = (admit[i] - due) + (w_end - admit[i]) + (rb[2] - w_end)
+            latency = emit[i] * 1e9 - (due - rec.t0_ns)
             assert admit[i] >= due - MS
-            assert abs(pieces - (emit[i] * 1e9 - (due - rec.t0_ns))) < MS
+            assert abs(pieces - latency) < MS + off_cpu
             checked += 1
     assert checked == len(ARRIVALS)
 
@@ -181,25 +245,32 @@ def test_stream_stats_is_live_during_serve(served):
 
 
 def test_each_span_is_a_host_event_on_the_profilers_clock(served):
+    """Each span's start and duration match its profiler event's within
+    1 ms of the program's own time; off-CPU time between the span's
+    clock stamps and the annotation's, read as in the test above, is
+    not the program's."""
     host, rec = served["host"], served["rec"]
     outer = [t for n, t, _ in host if n == OUTER]
     assert len(outer) == 1
     offset = outer[0] - served["outer_ns"]
+    off_outer = _off_cpu_ns(*served["outer_clocks"])
     events = {}
     for n, t, d in host:
         if n.startswith("serve."):
             events.setdefault(n, []).append((t, d))
     # serve.round holds the others and stays out of the profile
-    rows = [r for r in rec.rows if r[0] != "serve.round"]
-    assert set(events) == {r[0] for r in rows} == {
+    rows = [(r, c) for r, c in zip(rec.rows, rec.clocks)
+            if r[0] != "serve.round"]
+    assert set(events) == {r[0] for r, _ in rows} == {
         "serve.decode", "serve.admit", "serve.dispatch", "serve.prestage",
         "serve.wait", "serve.readback", "serve.idle"}
-    for name, s, e, _, _ in rows:
+    for (name, s, e, _, _), (entering, leaving) in rows:
+        off_in, off_out = _off_cpu_ns(*entering), _off_cpu_ns(*leaving)
         t = np.asarray([t for t, _ in events[name]])
         k = int(np.argmin(np.abs(t - (s + offset))))
         t_ev, d_ev = events[name][k]
-        assert abs(t_ev - (s + offset)) < MS
-        assert abs(d_ev - (e - s)) < MS
+        assert abs(t_ev - (s + offset)) < MS + off_in + off_outer
+        assert abs(d_ev - (e - s)) < MS + off_in + off_out
     assert len(rows) == sum(len(v) for v in events.values())
 
 
