@@ -206,11 +206,12 @@ class Bench:
         set-up steady; the programs each step built are logged, and the
         last pass should build none.
 
-        Every admission size and every count of lanes retiring at once
-        builds programs of its own (eager gathers and scatters of the
-        lane state), so the warm-up first admits a burst of each size up
-        to the pool width, of requests whose budget the init design
-        spends: each burst retires as a whole on admission."""
+        Every admission size builds programs of its own (the eager
+        scatters of the lane state; the readback builds one fetch program
+        per pool width whatever the count of lanes retiring), so the
+        warm-up first admits a burst of each size up to the pool width,
+        of requests whose budget the init design spends: each burst
+        retires as a whole on admission."""
         with CompileCounter() as cc:
             width = self.lanes // self.cfg["pools"]
             spread = self.cfg["pools"]       # admissions spread over pools

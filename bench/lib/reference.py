@@ -5,7 +5,16 @@ description in ``bench/archs/<arch>.json`` it builds the per-split-layer
 cost profile (device MACs, server MACs, bytes on the uplink), the
 paper's energy and delay model (Eq. 1-4), the constraint budgets, the
 channel anchor and the utility oracle, and then holds every solve the
-planner emitted to them:
+planner emitted to them.
+
+The profile is the architecture kind's own: ``network.kind`` names the
+file ``bench/kinds/<kind>.py``, whose ``profile(network)`` returns
+``(macs, boundary_bytes, tail_macs)``: the MACs of each of the L split
+layers, the bytes that cross the link after split ``l`` for ``l`` =
+0..L (index 0 is what the device sends when it runs nothing) and the
+MACs that only the server runs, after the last split layer. A kind
+module imports only the standard library and numpy. The rest of the
+reference is the same for every kind:
 
 * ``ledger_faults`` (exact): the ledger is well formed. Its length is
   within the budget, every split layer is a real one, the answer is the
@@ -36,6 +45,8 @@ from pathlib import Path
 
 import numpy as np
 
+from bench.lib.spec import load_module
+
 TOL = 1e-5
 
 # Section 6.1 of the paper: Raspberry Pi 4 device, Mac M4 server, an
@@ -50,65 +61,21 @@ QUANTUM = 100.0 / 64.0
 COMPLETION_FLOOR = 0.9
 
 
-# -- per-layer profiles from the architecture descriptions -------------------
-
-def _vgg(net):
-    hw, cin = net["input_hw"], net["input_ch"]
-    macs, outs = [], []
-    for p in net["plan"]:
-        if p == "M":
-            hw //= 2
-            macs.append(hw * hw * cin)
-            outs.append(hw * hw * cin)
-        else:
-            out = hw * hw * p
-            macs += [9 * cin * p * hw * hw, out]      # 3x3 conv, then ReLU
-            outs += [out, out]
-            cin = p
-    tail = 0.0
-    a = hw * hw * cin
-    for b in net["classifier"] + [net["n_classes"]]:
-        tail += a * b
-        a = b
-    return macs, outs, tail
-
-
-def _resnet(net):
-    hw = net["input_hw"] // 2
-    macs, outs = [49 * 3 * 64 * hw * hw], [hw * hw * 64]   # 7x7/2 stem
-    hw //= 2
-    macs.append(hw * hw * 64)                               # 3x3/2 max pool
-    outs.append(hw * hw * 64)
-    cin = 64
-    for s, (width, n) in enumerate(net["stages"]):
-        for b in range(n):
-            stride = 2 if (b == 0 and s > 0) else 1
-            cout, ho = 4 * width, hw // stride
-            m = (cin * width * hw * hw + 9 * width * width * ho * ho
-                 + width * cout * ho * ho)
-            if b == 0:
-                m += cin * cout * ho * ho                   # projection
-            macs.append(m)
-            outs.append(ho * ho * cout)
-            hw, cin = ho, cout
-    macs.append(hw * hw * cin)                              # global pool
-    outs.append(cin)
-    return macs, outs, cin * net["n_classes"]
-
-
 class Arch:
     """One architecture's cost profile and calibrated problem."""
 
-    def __init__(self, spec: dict):
+    def __init__(self, spec: dict, kinds_dir: Path):
         net = spec["network"]
-        bpe = net["bytes_per_elem"]
-        macs, outs, tail = (_vgg if net["kind"] == "vgg" else _resnet)(net)
-        raw = net["input_hw"] ** 2 * net["input_ch"]
+        kind = load_module(kinds_dir, "kind", net["kind"])
+        macs, boundary_bytes, tail = kind.profile(net)
         self.name = spec["arch"]
         self.L = len(macs)
+        if len(boundary_bytes) != self.L + 1:
+            raise ValueError(f"{self.name}: {len(boundary_bytes)} boundary "
+                             f"sizes for {self.L} split layers")
         self.cum = np.concatenate([[0.0], np.cumsum(macs, dtype=np.float64)])
         self.total = float(self.cum[-1] + tail)
-        self.bits = 8.0 * bpe * np.asarray([raw] + outs, np.float64)
+        self.bits = 8.0 * np.asarray(boundary_bytes, np.float64)
         self.p_min, self.p_max = map(float, spec["power_w"])
         b, u, a = spec["budgets"], spec["utility"], spec["anchor"]
         self.e_max, self.tau_max = b["e_max_j"], b["tau_max_s"]
@@ -215,10 +182,13 @@ class Arch:
 
 
 def load_archs(archs_dir: Path, names) -> dict:
+    """``Arch`` of each ``<archs_dir>/<name>.json``; the kinds' profiles
+    are the files of ``kinds/`` beside ``archs_dir``."""
+    kinds_dir = Path(archs_dir).parent / "kinds"
     out = {}
     for n in names:
         with open(Path(archs_dir) / f"{n}.json") as f:
-            out[n] = Arch(json.load(f))
+            out[n] = Arch(json.load(f), kinds_dir)
     return out
 
 

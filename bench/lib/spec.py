@@ -5,7 +5,13 @@ configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); a metric is computed by the reader
 ``bench/metrics/<stem>.py``, where the stem is the metric's name up to
 its first ``.``, and the request architectures' reference data is
-``bench/archs/<arch>.json``. Adding any of these is adding files.
+``bench/archs/<arch>.json``. An architecture's ``network.kind`` names
+the float64 profile of its kind, ``bench/kinds/<kind>.py``, whose
+``profile(network)`` returns ``(macs, boundary_bytes, tail_macs)``: the
+work of each of the L split layers, the bytes that cross the link after
+split ``l`` for ``l`` = 0..L (0: the device runs nothing) and the
+server-only work after the last split layer (see
+``bench/lib/reference.py``). Adding any of these is adding files.
 """
 from __future__ import annotations
 
@@ -21,6 +27,19 @@ def _name(kind: str, name: str) -> str:
     if not NAME.match(name or ""):
         raise ValueError(f"bad {kind} name {name!r}")
     return name
+
+
+def load_module(directory, kind: str, stem: str):
+    """The module ``<directory>/<stem>.py``, loaded from its file; the
+    stem has to be a name, so it holds no path."""
+    path = Path(directory) / f"{_name(kind, stem)}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} {stem!r}: {path} is missing")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Spec:
@@ -83,9 +102,4 @@ class Spec:
     def reader(self, metric: str):
         """``read(record) -> float | None`` of ``bench/metrics/<stem>.py``."""
         stem = _name("metric", metric).split(".", 1)[0]
-        path = self.dir / "metrics" / f"{stem}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{stem}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(self.dir / "metrics", "metric", stem).read
